@@ -504,6 +504,8 @@ def dist_difficulty_serve(n: int = 20_000, d: int = 32, k: int = 10,
 
 if __name__ == "__main__":
     from benchmarks.artifact import write_bench_artifact
+    from repro.launch import compile_cache
+    compile_cache.enable()
     out = {}
     for fn in (dist_sharded_search, dist_sharded_ivf_probe,
                dist_sharded_hnsw_beam, dist_residency,

@@ -355,6 +355,8 @@ def mutate_online_compaction(n: int = 8_000, d: int = 24,
 
 if __name__ == "__main__":
     from benchmarks.artifact import write_bench_artifact
+    from repro.launch import compile_cache
+    compile_cache.enable()
     out = {}
     for fn in (mutate_burst, mutate_online_compaction):
         rows, headline = fn()

@@ -86,6 +86,8 @@ def obs_tracing_overhead(repeats: int = 5):
 
 if __name__ == "__main__":
     from benchmarks.artifact import write_bench_artifact
+    from repro.launch import compile_cache
+    compile_cache.enable()
     out_rows, out_headline = obs_tracing_overhead()
     print(out_headline)
     print("wrote", write_bench_artifact(

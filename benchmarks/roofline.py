@@ -5,7 +5,8 @@ from the dry-run's compiled artifacts.
   memory     = HLO_bytes / HBM_bw               (per-device kernel traffic)
   collective = collective_bytes / link_bw       (per-device wire bytes)
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware peaks come from PEAKS, keyed by jax's `device_kind`; the dry-run
+models a TPU v5e (DRYRUN_DEVICE_KIND).
 MODEL_FLOPS = 6*N*D (train, dense), 6*N_active*D (MoE), 2*N*D (prefill),
 2*N_active*tokens (decode) — the "useful compute" yardstick; the ratio
 MODEL_FLOPS / (HLO_FLOPs * chips) exposes remat/redundancy waste.
@@ -18,9 +19,23 @@ from typing import Dict, Optional
 
 import numpy as np
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s
-LINK_BW = 50e9           # bytes/s/link (conservative: single-link model)
+# Published per-chip peaks by `jax.devices()[i].device_kind`. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM);
+# link_bw is a conservative single-link model of the 1,600 Gbit/s ICI.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of `device_kind`; an unknown device is an error,
+    never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to benchmarks/roofline.py PEAKS with their source")
+    return PEAKS[device_kind]
 
 _PARAM_COUNTS: Dict[str, Dict[str, float]] = {}
 
@@ -65,17 +80,18 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
     if rec.get("status") != "ok":
         return None
     from repro.configs.base import SHAPES
+    peak = peaks(DRYRUN_DEVICE_KIND)
     cell = SHAPES[rec["shape"]]
     chips = rec["num_devices"]
-    t_c = rec["hlo_flops"] / PEAK_FLOPS
-    t_m = rec["hlo_bytes"] / HBM_BW
-    t_x = rec["collectives"]["total"] / LINK_BW
+    t_c = rec["hlo_flops"] / peak["flops"]
+    t_m = rec["hlo_bytes"] / peak["hbm_bw"]
+    t_x = rec["collectives"]["total"] / peak["link_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])[0]
     mf = model_flops(rec["arch"], cell.kind, cell.seq_len, cell.global_batch)
     useful = mf / max(rec["hlo_flops"] * chips, 1.0)
     bound = max(t_c, t_m, t_x)
-    mfu_bound = (mf / chips / PEAK_FLOPS) / max(bound, 1e-12)
+    mfu_bound = (mf / chips / peak["flops"]) / max(bound, 1e-12)
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "kind": cell.kind,

@@ -17,6 +17,9 @@ from benchmarks.artifact import write_bench_artifact
 
 def main() -> None:
     from benchmarks import dist_search, mutate, obs, paper_tables as pt
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     benches = [
         ("obs_tracing_overhead", obs.obs_tracing_overhead),

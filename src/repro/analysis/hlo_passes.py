@@ -5,7 +5,10 @@ splitting, trip-count-weighted collective accounting). Each pass takes
 compiled HLO text (`jit(f).lower(*args).compile().as_text()`) and
 returns Findings anchored at the `metadata={source_file= source_line=}`
 XLA carries for every instruction, so a gate failure points at the
-Python line that built the bad op.
+Python line that built the bad op. Newer jax writes the location as
+`stack_frame_id=N` into the module's FileNames / FileLocations /
+StackFrames tables instead; `_inline_stack_frames` rewrites that form
+into the inline one before a pass reads it.
 """
 from __future__ import annotations
 
@@ -27,8 +30,45 @@ _PARAM_RE = re.compile(
     r"=\s*([a-z0-9]+)\[([\d,]*)\]\S*\s+parameter\((\d+)\)")
 _META_FILE_RE = re.compile(r'source_file="([^"]+)"')
 _META_LINE_RE = re.compile(r"source_line=(\d+)")
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"^(\d+) (.*)$")
 _DEF_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=")
 _OPERAND_RE = re.compile(r"%([\w\.\-]+)")
+
+
+def _inline_stack_frames(hlo: str) -> str:
+    """Replace every `stack_frame_id=N` with the innermost frame's
+    `source_file="..." source_line=L` from the module's tables (a no-op
+    on HLO that already carries inline locations)."""
+    tables: Dict[str, Dict[int, str]] = {}
+    cur: Optional[Dict[int, str]] = None
+    for line in hlo.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            cur = tables.setdefault(line, {})
+            continue
+        m = _TABLE_ROW_RE.match(line) if cur is not None else None
+        if m:
+            cur[int(m.group(1))] = m.group(2)
+        else:
+            cur = None
+    frames = tables.get("StackFrames")
+    if not frames:
+        return hlo
+    files = tables.get("FileNames", {})
+    locs = tables.get("FileLocations", {})
+
+    def field(row: str, key: str) -> int:
+        return int(re.search(r"\b" + key + r"=(\d+)", row).group(1))
+
+    def inline(m: "re.Match[str]") -> str:
+        loc = locs.get(field(frames[int(m.group(1))], "file_location_id"))
+        if loc is None:
+            return m.group(0)
+        path = files.get(field(loc, "file_name_id"), '""')
+        return f"source_file={path} source_line={field(loc, 'line')}"
+
+    return _FRAME_ID_RE.sub(inline, hlo)
 
 
 def _source_loc(line: str) -> Tuple[Optional[str], Optional[int]]:
@@ -50,6 +90,7 @@ def replicated_constants(entry: str, hlo: str,
     the jit boundary as an argument.
     """
     out: List[Finding] = []
+    hlo = _inline_stack_frames(hlo)
     for line in hlo.splitlines():
         m = _CONST_RE.search(line)
         if not m:
@@ -58,6 +99,11 @@ def replicated_constants(entry: str, hlo: str,
         if nbytes < threshold:
             continue
         f, ln = _source_loc(line)
+        if f is None:
+            # jax 0.9 emits captured constants without op metadata:
+            # anchor at the op that consumes the constant instead.
+            nm = _DEF_NAME_RE.match(line)
+            f, ln = _use_loc(hlo, nm.group(1) if nm else "")
         out.append(Finding(
             "replicated-constant", entry,
             f"{m.group(1)} ({nbytes} bytes) baked into the compiled "
@@ -95,7 +141,7 @@ def unpartitionable_topk(entry: str, hlo: str, *, max_hops: int = 6
     dim-0 gather far upstream of an unrelated sort stays quiet.
     """
     out: List[Finding] = []
-    for lines in _split_computations(hlo).values():
+    for lines in _split_computations(_inline_stack_frames(hlo)).values():
         defs = _def_map(lines)
         gathers = {name for name, line in defs.items()
                    if ("all-gather" in line.split("=", 1)[-1][:64]
@@ -178,16 +224,16 @@ def _entry_params(hlo: str) -> Dict[int, Tuple[str, str, Tuple[int, ...]]]:
     return params
 
 
-def _param_use_loc(hlo: str, name: str
-                   ) -> Tuple[Optional[str], Optional[int]]:
-    """Source anchor for a parameter: the first instruction CONSUMING
-    `%name` that carries metadata. Parameter instructions themselves
-    have no source location — the array was built in Python, not by an
-    op — so the finding points at the code that reads it."""
+def _use_loc(hlo: str, name: str) -> Tuple[Optional[str], Optional[int]]:
+    """Source anchor for a parameter or constant: the first instruction
+    CONSUMING `%name` that carries metadata. Parameter (and captured
+    constant) instructions have no source location — the array was
+    built in Python, not by an op — so the finding points at the code
+    that reads it."""
     if not name:
         return None, None
     pat = re.compile(r"%" + re.escape(name) + r"\b")
-    for line in hlo.splitlines():
+    for line in _inline_stack_frames(hlo).splitlines():
         if " parameter(" in line:
             continue
         if not pat.search(line.split("=", 1)[-1]):
@@ -242,7 +288,7 @@ def resident_bytes(entry: str, hlo_small: str, hlo_large: str,
             continue
         if dt not in ("f32", "f64", "bf16", "f16"):
             continue
-        f, ln = _param_use_loc(hlo_large, name)
+        f, ln = _use_loc(hlo_large, name)
         out.append(Finding(
             "resident-bytes", entry,
             f"N-scaled vector payload parameter({num}) is device-"

@@ -8,7 +8,7 @@ generalizes across ANNS methods whose search is iterative.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 
@@ -60,7 +60,8 @@ def ivf_engine(index: ivf_lib.IVFIndex, *, k: int, nprobe: int) -> Engine:
 
 
 def sharded_ivf_engine(index: ivf_lib.IVFIndex, mesh, *, k: int, nprobe: int,
-                       use_kernel: bool = True, interpret: bool = True,
+                       use_kernel: bool = True,
+                       interpret: Optional[bool] = None,
                        pin_merge: bool = True) -> Engine:
     """ShardedIVFEngine: the IVF probe loop over a cap-sharded bucket
     store (dist.place_index + dist.collectives.make_sharded_probe_step).
@@ -122,7 +123,7 @@ def hnsw_engine(index: hnsw_lib.HNSWIndex, *, k: int, ef: int,
 
 
 def mutable_engine(base_engine: Engine, delta, *,
-                   interpret: bool = True) -> Engine:
+                   interpret: Optional[bool] = None) -> Engine:
     """MutableEngine: wrap ANY engine (single-device or sharded) with a
     delta tier — init adds one brute-force delta scan (fused l2_topk),
     step is the base probe/beam step, and the top-k getters merge the

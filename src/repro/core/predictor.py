@@ -3,8 +3,8 @@
 Two inference paths, numerically identical (tests assert it):
   * XLA path (gbdt.infer.predict_efficient) — used on CPU and inside
     lowered dry-run graphs,
-  * Pallas path (kernels.gbdt_predict) — VMEM-resident ensemble, the TPU
-    target; validated in interpret mode.
+  * Pallas path (kernels.gbdt_predict) — VMEM-resident ensemble, compiled
+    on a TPU, interpreted on the CPU (kernels.backend).
 """
 from __future__ import annotations
 

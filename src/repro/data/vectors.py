@@ -30,15 +30,33 @@ class VectorDataset(NamedTuple):
 def make_dataset(n: int = 100_000, d: int = 64, *, num_learn: int = 10_000,
                  num_queries: int = 1_000, clusters: int = 256,
                  cluster_std: float = 1.0, center_scale: float = 4.0,
-                 seed: int = 0, name: str = "synth") -> VectorDataset:
+                 intrinsic_dim: int = 0, seed: int = 0,
+                 name: str = "synth") -> VectorDataset:
     """Clustered mixture. center_scale/cluster_std controls separation
-    (higher = more clustered = easier queries, lower LID)."""
+    (higher = more clustered = easier queries, lower LID).
+
+    `intrinsic_dim` r > 0 draws the mixture in r dimensions and lifts it
+    into d through a random orthonormal map plus isotropic noise of std
+    0.1 — low intrinsic dimension in a high ambient one, as in real
+    descriptor collections (SIFT's is far below its 128). 0 draws the
+    mixture in all d dimensions."""
     rng = np.random.default_rng(seed)
-    centers = rng.normal(size=(clusters, d)) * center_scale
+    r = intrinsic_dim or d
+    centers = rng.normal(size=(clusters, r)) * center_scale
     total = n + num_learn + num_queries
 
     assign = rng.integers(0, clusters, size=total)
-    pts = centers[assign] + rng.normal(size=(total, d)) * cluster_std
+    pts = centers[assign] + rng.normal(size=(total, r)) * cluster_std
+    if intrinsic_dim:
+        basis = np.linalg.qr(rng.normal(size=(d, r)))[0].T      # [r, d]
+
+        def lift(z: np.ndarray) -> np.ndarray:
+            return (z @ basis
+                    + rng.normal(size=(z.shape[0], d)) * 0.1)
+        pts = lift(pts)
+    else:
+        def lift(z: np.ndarray) -> np.ndarray:
+            return z
     pts = pts.astype(np.float32)
     base = pts[:n]
     learn = pts[n:n + num_learn]
@@ -60,9 +78,9 @@ def make_dataset(n: int = 100_000, d: int = 64, *, num_learn: int = 10_000,
         sigma = np.sqrt(pcts * norms / d)
         learn[noisy_sel] += (rng.normal(size=(n_noisy, d)) * sigma
                              ).astype(np.float32)
-        far_centers = rng.normal(size=(n_far, d)) * center_scale
-        learn[far_sel] = (far_centers + rng.normal(size=(n_far, d))
-                          * cluster_std).astype(np.float32)
+        far_centers = rng.normal(size=(n_far, r)) * center_scale
+        learn[far_sel] = lift(far_centers + rng.normal(size=(n_far, r))
+                              * cluster_std).astype(np.float32)
     return VectorDataset(base=base, learn=learn, queries=queries, name=name)
 
 

@@ -33,11 +33,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.padding import (PAD_DIST, PAD_ID, PAD_SQNORM, pad_dists,
@@ -45,6 +45,7 @@ from repro.core.padding import (PAD_DIST, PAD_ID, PAD_SQNORM, pad_dists,
 from repro.kernels import ops
 
 SHARD_AXIS = "model"
+_EXACT = jax.lax.Precision.HIGHEST
 
 # Slot-pool serving (serve.engine.DarthServer on a make_serve_mesh):
 # when the mesh carries a "hosts" axis, the search state's slot (batch)
@@ -86,7 +87,8 @@ def merge_topk(cand_d: jax.Array, cand_i: jax.Array, k: int
 
 
 def make_sharded_flat_search(mesh: Mesh, k: int, *, axis: str = SHARD_AXIS,
-                             use_kernel: bool = True, interpret: bool = True
+                             use_kernel: bool = True,
+                             interpret: Optional[bool] = None
                              ) -> Callable[[jax.Array, jax.Array],
                                            Tuple[jax.Array, jax.Array]]:
     """Exact flat k-NN over a database sharded on `axis`.
@@ -94,19 +96,22 @@ def make_sharded_flat_search(mesh: Mesh, k: int, *, axis: str = SHARD_AXIS,
     Returns fn(q [B, D], x [N, D]) -> (dist [B, k] ascending, idx [B, k]),
     numerically matching index.flat.search on any shard count (including
     the 1-device host mesh). Queries are replicated; per-shard local
-    top-k uses the fused Pallas kernel (interpret-mode on CPU), the
-    cross-shard merge is one tiled all-gather of [B, k] + top_k.
+    top-k uses the fused Pallas kernel, the cross-shard merge is one
+    tiled all-gather of [B, k] + top_k. This is ground truth, so the
+    q.x contraction runs at HIGHEST precision on either path.
     """
     nshards = shard_count(mesh, axis)
 
     def local_topk(q, x_loc, sqn_loc):
         if use_kernel:
             d_loc, i_loc = ops.l2_topk(q, x_loc, k=k, x_sqnorm=sqn_loc,
+                                       precision=_EXACT,
                                        interpret=interpret)
         else:  # pure-XLA: padded rows enter with sqn=+inf, never win
             qf = q.astype(jnp.float32)
             d2 = (jnp.sum(qf ** 2, 1)[:, None] + sqn_loc[None, :]
-                  - 2.0 * qf @ x_loc.astype(jnp.float32).T)
+                  - 2.0 * jnp.matmul(qf, x_loc.astype(jnp.float32).T,
+                                     precision=_EXACT))
             if d2.shape[1] < k:  # fewer local rows than k: pad candidates
                 d2 = jnp.pad(d2, ((0, 0), (0, k - d2.shape[1])),
                              constant_values=PAD_DIST)
@@ -124,7 +129,7 @@ def make_sharded_flat_search(mesh: Mesh, k: int, *, axis: str = SHARD_AXIS,
         local_topk, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis)),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def search(q: jax.Array, x: jax.Array
@@ -227,7 +232,7 @@ def make_sharded_ivf_init(mesh: Mesh, *, axis: str = SHARD_AXIS
                 mesh=mesh,
                 in_specs=(P(None, None), P(bh, None), P(bh, None)),
                 out_specs=(P(bh, None), P(bh)),
-                check_rep=False)
+                check_vma=False)
             order, first_nn = rank(index.centroids, qf, qsq)
         return ivf_lib.fresh_state(qf, qsq, order, first_nn, k)
 
@@ -237,7 +242,8 @@ def make_sharded_ivf_init(mesh: Mesh, *, axis: str = SHARD_AXIS
 
 
 def make_sharded_probe_step(mesh: Mesh, *, axis: str = SHARD_AXIS,
-                            use_kernel: bool = True, interpret: bool = True,
+                            use_kernel: bool = True,
+                            interpret: Optional[bool] = None,
                             pin_merge: bool = True
                             ) -> Callable[[Any, Any], Any]:
     """One IVF probe step over a cap-sharded bucket store.
@@ -359,7 +365,7 @@ def make_sharded_probe_step(mesh: Mesh, *, axis: str = SHARD_AXIS,
                       P(bh), P(bh), P(None, axis, None), P(None, axis),
                       P(None, axis)),
             out_specs=(P(bh, None), P(bh, None), P(bh)),
-            check_rep=False)
+            check_vma=False)
         out_d, out_i, cnt = sharded(
             q_eff, bias, s.topk_d, s.topk_i, slot, hot,
             index.bucket_vecs, index.bucket_sqnorm, index.bucket_ids)
@@ -457,7 +463,7 @@ def make_sharded_beam_step(mesh: Mesh, *, axis: str = SHARD_AXIS,
             mesh=mesh,
             in_specs=(P(bh, None), P(bh, None), P(bh, None)),
             out_specs=(P(bh, None), P(bh, None), P(bh, None)),
-            check_rep=False)
+            check_vma=False)
         return fn(cand_d, cand_i, cand_e)
 
     def beam_step(index: Any, s: Any, *, k: int) -> Any:
@@ -540,7 +546,7 @@ def make_sharded_beam_step(mesh: Mesh, *, axis: str = SHARD_AXIS,
             in_specs=(P(bh, None), P(bh, None), P(bh), P(bh),
                       P(axis, None), P(axis), P(axis, None), P(bh, axis)),
             out_specs=(P(bh, None), P(bh, None), P(bh, axis)),
-            check_rep=False)
+            check_vma=False)
         nbrs, dist_all, visited = sharded(
             s.q, s.qsq, sel_id_safe, act,
             index.vectors, index.sqnorm, index.neighbors, s.visited)

@@ -303,7 +303,8 @@ def search(index: IVFIndex, q: jax.Array, *, k: int,
 
 
 def search_sharded(index: IVFIndex, q: jax.Array, *, k: int, nprobe: int,
-                   mesh, use_kernel: bool = True, interpret: bool = True
+                   mesh, use_kernel: bool = True,
+                   interpret: Optional[bool] = None
                    ) -> Tuple[jax.Array, jax.Array, IVFSearchState]:
     """Plain IVF search through the shard_map probe step: `index` must be
     placed with dist.place_index(index, mesh) (cap dim split over the

@@ -7,7 +7,9 @@ bucket_topk   fused IVF probe: per-query gathered bucket distances merged
 gbdt_predict  VMEM-resident GBDT ensemble inference (the recall predictor)
 
 Each kernel has a pure-jnp oracle in ref.py and a jit'd public wrapper in
-ops.py; tests sweep shapes/dtypes in interpret mode against the oracles.
+ops.py. backend.py decides how they run: compiled on a TPU, interpreted
+on the CPU; tests sweep shapes/dtypes in interpret mode against the
+oracles.
 """
 from repro.kernels import ops, ref
 from repro.kernels.ops import bucket_topk, gbdt_predict, l2_topk

@@ -14,11 +14,14 @@ whole active batch costs less than a single IVF bucket probe.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 
 def _gbdt_kernel(x_ref, feat_ref, thr_ref, leaf_ref, out_ref, *,
@@ -56,7 +59,7 @@ def _gbdt_kernel(x_ref, feat_ref, thr_ref, leaf_ref, out_ref, *,
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def gbdt_predict_padded(x: jax.Array, feat: jax.Array, thr: jax.Array,
                         leaf: jax.Array, *, bq: int = 64,
-                        interpret: bool = False) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """Pre-padded kernel entry. x: [B, F], B % bq == 0. Returns [B] (no base)."""
     b, num_feat = x.shape
     assert b % bq == 0, (b, bq)
@@ -74,8 +77,8 @@ def gbdt_predict_padded(x: jax.Array, feat: jax.Array, thr: jax.Array,
         ],
         out_specs=pl.BlockSpec((bq, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, feat, thr, leaf)
     return out[:, 0]

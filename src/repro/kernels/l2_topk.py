@@ -22,17 +22,20 @@ padded db rows carry x_sqnorm=+inf so they never enter the top-k.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 _NEG_INF = float("-inf")
 
 
-def _l2_topk_kernel(q_ref, x_ref, xsq_ref, outd_ref, outi_ref, *, k: int, bn: int):
+def _l2_topk_kernel(q_ref, x_ref, xsq_ref, outd_ref, outi_ref, *, k: int,
+                    bn: int, precision):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -46,6 +49,7 @@ def _l2_topk_kernel(q_ref, x_ref, xsq_ref, outd_ref, outi_ref, *, k: int, bn: in
 
     # MXU: [bq, bn] partial distances (missing rank-invariant ||q||^2).
     dots = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                               precision=precision,
                                preferred_element_type=jnp.float32)
     tile_d = xsq - 2.0 * dots                     # [bq, bn]
     base = j * bn
@@ -81,11 +85,17 @@ def _l2_topk_kernel(q_ref, x_ref, xsq_ref, outd_ref, outi_ref, *, k: int, bn: in
     outi_ref[...] = new_i
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bq", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "bq", "bn", "precision",
+                                             "interpret"))
 def l2_topk_padded(q: jax.Array, x: jax.Array, x_sqnorm: jax.Array, *,
                    k: int, bq: int = 128, bn: int = 512,
-                   interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+                   precision: Optional[jax.lax.Precision] = None,
+                   interpret: Optional[bool] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
     """Pre-padded fused distance+topk. See ops.l2_topk for the public API.
+
+    `precision` is the MXU contraction precision of the q.x tile (None is
+    Mosaic's default; HIGHEST contracts in full f32).
 
     q: [B, D] (B % bq == 0), x: [N, D] (N % bn == 0), x_sqnorm: [N].
     Returns (dist [B, k] ascending — WITHOUT the ||q||^2 term, idx [B, k]).
@@ -96,7 +106,8 @@ def l2_topk_padded(q: jax.Array, x: jax.Array, x_sqnorm: jax.Array, *,
     grid = (b // bq, n // bn)
     xsq2d = x_sqnorm.reshape(1, n)
 
-    kernel = functools.partial(_l2_topk_kernel, k=k, bn=bn)
+    kernel = functools.partial(_l2_topk_kernel, k=k, bn=bn,
+                               precision=precision)
     outd, outi = pl.pallas_call(
         kernel,
         grid=grid,
@@ -113,8 +124,8 @@ def l2_topk_padded(q: jax.Array, x: jax.Array, x_sqnorm: jax.Array, *,
             jax.ShapeDtypeStruct((b, k), jnp.float32),
             jax.ShapeDtypeStruct((b, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, x, xsq2d)
     return outd, outi

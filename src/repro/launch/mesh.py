@@ -3,22 +3,38 @@
 A FUNCTION, not a module-level constant: importing this module never
 touches jax device state (device count is locked at first jax init, and
 tests/benches must see 1 CPU device while the dry-run sees 512
-placeholders)."""
+placeholders).
+
+Every mesh is built with Auto axes (`auto_mesh`): jax.make_mesh defaults
+to Explicit axes, which with_sharding_constraint and the GSPMD-placed
+serving state reject."""
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """jax.make_mesh with every axis Auto (sharding propagated by GSPMD).
+    `devices` defaults to all visible devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def make_search_mesh(num_shards: int = 0):
@@ -33,7 +49,7 @@ def make_search_mesh(num_shards: int = 0):
             f"--shards {n} needs {n} devices but only "
             f"{jax.device_count()} visible; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} for a smoke run")
-    return jax.make_mesh((n,), ("model",))
+    return auto_mesh((n,), ("model",))
 
 
 def make_serve_mesh(hosts: int = 1, shards: int = 0):
@@ -54,7 +70,7 @@ def make_serve_mesh(hosts: int = 1, shards: int = 0):
             f"but only {jax.device_count()} visible; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={hosts * n} for a "
             f"smoke run")
-    return jax.make_mesh((hosts, n), ("hosts", "model"))
+    return auto_mesh((hosts, n), ("hosts", "model"))
 
 
 def describe(mesh) -> str:

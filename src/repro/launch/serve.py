@@ -58,6 +58,7 @@ from repro import dist, mutate
 from repro.core import api, engines, training
 from repro.data import vectors
 from repro.index import flat, hnsw, ivf
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.serve import DarthServer
 from repro.utils import hlo as hlo_lib
@@ -150,6 +151,7 @@ def main() -> None:
                          "write the Prometheus exposition page + JSONL "
                          "event log to --trace DIR (or results/)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     targets = [float(t) for t in args.targets.split(",")]
     ds = vectors.make_dataset(n=args.n, d=args.dim, num_learn=args.learn,

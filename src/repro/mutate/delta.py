@@ -22,7 +22,7 @@ serving chunks.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +90,7 @@ def live_count(delta: DeltaTier) -> jax.Array:
 
 
 def delta_topk(delta: DeltaTier, q: jax.Array, k: int, *,
-               interpret: bool = True
+               interpret: Optional[bool] = None
                ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Brute-force scan of the delta tier with the fused l2_topk kernel.
 

@@ -26,7 +26,7 @@ tests/test_mutate.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +75,7 @@ class MutableSearchState:
 
 
 def mutable_engine(base: engines_lib.Engine, delta: delta_lib.DeltaTier, *,
-                   interpret: bool = True) -> engines_lib.Engine:
+                   interpret: Optional[bool] = None) -> engines_lib.Engine:
     """Wrap `base` so search covers base + delta minus tombstones."""
     k = base.k
     if delta.capacity < k:

@@ -22,7 +22,9 @@ def build_bad():
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((jax.device_count(),), ("hosts",))
+    from repro.launch.mesh import auto_mesh
+
+    mesh = auto_mesh((jax.device_count(),), ("hosts",))
     cand = jax.device_put(
         jnp.asarray(np.random.default_rng(0).normal(
             size=(8 * jax.device_count(), 128)).astype(np.float32)),

@@ -1,18 +1,8 @@
 import os
-import sys
 
 # Tests run on the single real CPU device (the dry-run, and ONLY the
 # dry-run, uses 512 placeholder devices via its own env line).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# Property tests use hypothesis when installed (CI does, via
-# requirements.txt); offline containers fall back to the deterministic
-# shim in tests/_vendor that covers the API subset the suite needs.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_vendor"))
-
 
 import pytest
 

@@ -70,3 +70,16 @@ def test_lid_estimator():
         return float(np.median(vectors.local_intrinsic_dimensionality(
             np.asarray(dists))))
     assert lid_of(32) > lid_of(4)
+
+
+def test_make_dataset_intrinsic_dim_is_low_rank():
+    """intrinsic_dim=r lifts an r-dim mixture into d dims: r singular
+    directions carry the spread, the rest only the small lift noise."""
+    ds = vectors.make_dataset(n=2000, d=64, num_learn=100, num_queries=50,
+                              clusters=16, center_scale=1.0,
+                              intrinsic_dim=8, seed=0)
+    assert ds.base.shape == (2000, 64) and ds.learn.shape == (100, 64)
+    assert ds.queries.shape == (50, 64) and ds.base.dtype == np.float32
+    x = np.concatenate([ds.base, ds.queries])
+    sv = np.linalg.svd(x - x.mean(0), compute_uv=False)
+    assert sv[8] < 0.2 * sv[7]

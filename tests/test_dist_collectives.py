@@ -12,10 +12,11 @@ import pytest
 
 from repro.dist import collectives
 from repro.index import flat
+from repro.launch.mesh import auto_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("model",))
+    return auto_mesh((1,), ("model",))
 
 
 def test_merge_topk_masks_padding_ids():
@@ -103,10 +104,11 @@ import numpy as np
 sys.path.insert(0, "src")
 from repro.dist import collectives
 from repro.index import flat
+from repro.launch.mesh import auto_mesh
 
 # N deliberately NOT divisible by the 8-way model axis: 1001 = 8*125 + 1,
 # so 7 padded rows exist on the last shard and must never surface.
-mesh = jax.make_mesh((8,), ("model",))
+mesh = auto_mesh((8,), ("model",))
 rng = np.random.default_rng(0)
 n, d, b, k = 1001, 16, 32, 10
 x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
@@ -147,12 +149,12 @@ def test_elastic_restore_from_mesh(tmp_path):
 
     from repro import ckpt
 
-    mesh_a = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_a = auto_mesh((1, 1), ("data", "model"))
     w = jax.device_put(jnp.arange(32.0).reshape(4, 8),
                        NamedSharding(mesh_a, P("data", "model")))
     ckpt.save(str(tmp_path), 1, {"w": w})
 
-    mesh_b = jax.make_mesh((1,), ("model",))
+    mesh_b = auto_mesh((1,), ("model",))
     like = {"w": jax.ShapeDtypeStruct((4, 8), jnp.float32)}
     restored, meta = ckpt.restore(str(tmp_path), like, shardings=mesh_b)
     np.testing.assert_allclose(np.asarray(restored["w"]), np.asarray(w))

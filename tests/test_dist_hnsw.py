@@ -16,10 +16,11 @@ import pytest
 from repro import dist
 from repro.core import darth_search, engines
 from repro.index import hnsw
+from repro.launch.mesh import auto_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("model",))
+    return auto_mesh((1,), ("model",))
 
 
 @pytest.fixture(scope="module")

@@ -16,10 +16,11 @@ import pytest
 from repro import dist
 from repro.core import darth_search, engines
 from repro.index import ivf
+from repro.launch.mesh import auto_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("model",))
+    return auto_mesh((1,), ("model",))
 
 
 @pytest.fixture(scope="module")
@@ -40,8 +41,13 @@ def test_sharded_probe_matches_single_device(small_ivf, quantize):
     q = jnp.asarray(ds.queries[:16])
     d0, i0, s0 = ivf.search(index, q, k=5, nprobe=6)
     d1, i1, s1 = ivf.search_sharded(placed, q, k=5, nprobe=6, mesh=mesh)
-    np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), atol=1e-4)
+    # The kernel reduces q.x on the VPU, the single-device step with an
+    # einsum: the f32 summation order differs and sqn - 2 q.x + ||q||^2
+    # cancels, so distances agree to a relative 2e-5 (1.6e-5 seen)
+    # while the ids must match exactly.
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=2e-5,
+                               atol=0)
     np.testing.assert_array_equal(np.asarray(s0.ndis), np.asarray(s1.ndis))
     np.testing.assert_array_equal(np.asarray(s0.ninserts),
                                   np.asarray(s1.ninserts))
@@ -103,9 +109,10 @@ ds = vectors.make_dataset(n=2000, d=16, num_learn=64, num_queries=32,
 q = jnp.asarray(ds.queries[:16])
 out = {"ndev": jax.device_count(), "cases": []}
 for quantize in (False, True):
-    # cap_round=1 -> cap is the raw max bucket size (217 for this seed),
-    # NOT a multiple of 2 or 4: place_index must pad the cap dim.
-    index = ivf.build(ds.base, nlist=16, seed=0, cap_round=1,
+    # cap_round=1 -> cap is the raw max bucket size (217 for this build
+    # seed under jax 0.9), NOT a multiple of 2 or 4: place_index must pad
+    # the cap dim.
+    index = ivf.build(ds.base, nlist=16, seed=2, cap_round=1,
                       quantize=quantize)
     d0, i0, s0 = ivf.search(index, q, k=5, nprobe=6)
     for nsh in (1, 2, 4):
@@ -122,7 +129,7 @@ for quantize in (False, True):
             "pad_ok": bool((ids_pad == -1).all()
                            and np.isposinf(sqn_pad).all()),
             "d_ok": bool(np.allclose(np.asarray(d0), np.asarray(d1),
-                                     atol=1e-4)),
+                                     rtol=2e-5, atol=0)),
             "i_ok": bool(np.array_equal(np.asarray(i0), np.asarray(i1))),
             "ndis_ok": bool(np.array_equal(np.asarray(s0.ndis),
                                            np.asarray(s1.ndis))),

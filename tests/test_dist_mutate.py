@@ -19,10 +19,11 @@ from repro import dist, mutate
 from repro.core import darth_search, engines
 from repro.data import vectors
 from repro.index import ivf
+from repro.launch.mesh import auto_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("model",))
+    return auto_mesh((1,), ("model",))
 
 
 @pytest.fixture(scope="module")
@@ -49,10 +50,12 @@ def test_sharded_mutable_matches_single_device(mutated_ivf):
     assert sh.name == "ivf-sharded+delta"
     ws0 = darth_search.plain_search(ref, q)
     ws1 = darth_search.plain_search(sh, q)
-    np.testing.assert_allclose(np.asarray(ref.topk_d(ws0)),
-                               np.asarray(sh.topk_d(ws1)), atol=1e-4)
+    # kernel (sharded) vs einsum (single-device) distances: ids exact,
+    # distances to a relative 2e-5 (f32 summation order)
     np.testing.assert_array_equal(np.asarray(ref.topk_i(ws0)),
                                   np.asarray(sh.topk_i(ws1)))
+    np.testing.assert_allclose(np.asarray(ref.topk_d(ws0)),
+                               np.asarray(sh.topk_d(ws1)), rtol=2e-5, atol=0)
     np.testing.assert_array_equal(np.asarray(ws0.ndis), np.asarray(ws1.ndis))
     np.testing.assert_array_equal(np.asarray(ws0.ninserts),
                                   np.asarray(ws1.ninserts))

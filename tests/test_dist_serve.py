@@ -18,7 +18,7 @@ from repro.launch import mesh as mesh_lib
 
 
 def _serve_mesh1():
-    return jax.make_mesh((1, 1), ("hosts", "model"))
+    return mesh_lib.auto_mesh((1, 1), ("hosts", "model"))
 
 
 class _FakeServeMesh:
@@ -123,8 +123,10 @@ def test_serve_mesh_single_device_serves():
     res, stats = server.serve(ds.queries, rts)
     assert stats.completed == ref_stats.completed == 32
     for a, b in zip(ref, res):
-        np.testing.assert_allclose(a[0], b[0], atol=1e-4)
+        # kernel (sharded) vs einsum (meshless) distances: ids exact,
+        # distances to a relative 2e-5 (f32 summation order)
         np.testing.assert_array_equal(a[1], b[1])
+        np.testing.assert_allclose(a[0], b[0], rtol=2e-5, atol=0)
     assert stats.ndis_harvested == ref_stats.ndis_harvested
 
 

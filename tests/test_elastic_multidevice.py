@@ -23,11 +23,12 @@ sys.path.insert(0, "src")
 from repro import ckpt
 from repro.dist import sharding as sh
 from repro.utils import meshctx
+from repro.launch.mesh import auto_mesh
 
 tmp = sys.argv[1]
 
 # --- phase 1: "train" on a (4, 2) mesh, save sharded state ---
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = auto_mesh((4, 2), ("data", "model"))
 w = jnp.arange(64.0, dtype=jnp.float32).reshape(8, 8)
 sh_a = NamedSharding(mesh_a, P("data", "model"))
 w_a = jax.device_put(w, sh_a)
@@ -40,7 +41,7 @@ w_a = step(w_a)
 ckpt.save(tmp, 1, {"w": w_a})
 
 # --- phase 2: restore onto a (2, 4) mesh (elastic reshard) ---
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = auto_mesh((2, 4), ("data", "model"))
 sh_b = NamedSharding(mesh_b, P("data", "model"))
 like = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
 restored, meta = ckpt.restore(tmp, like, shardings={"w": sh_b})

@@ -118,3 +118,45 @@ def test_bucket_topk_property(b, c, d, k):
     dk_, ik_ = ops.bucket_topk(q, vecs, sqn, ids, run_d, run_i)
     dr, ir = ref.bucket_topk_ref(q, vecs, sqn, ids, run_d, run_i)
     np.testing.assert_allclose(np.asarray(dk_), np.asarray(dr), atol=1e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+def test_bucket_probe_tiled_matches_untiled(dtype):
+    """Scanning a bucket in C tiles (running top-k and insert count kept
+    across tiles) gives exactly the one-tile result, ties included."""
+    rng = np.random.default_rng(7)
+    b, c, d, k = 6, 1100, 16, 10
+    if dtype == jnp.int8:
+        raw = rng.integers(-127, 128, (b, c, d))
+    else:
+        raw = rng.normal(size=(b, c, d))
+    # q sits on column 3 and column 700 repeats it: two zero distances,
+    # one on each side of a tile edge, whose order must survive tiling
+    raw[:, 700] = raw[:, 3]
+    q = jnp.asarray(raw[:, 3], jnp.float32)
+    vecs = jnp.asarray(raw, dtype)
+    sqn = jnp.sum(vecs.astype(jnp.float32) ** 2, axis=2)
+    ids = rng.integers(0, 10**6, (b, c))
+    ids[rng.random((b, c)) < 0.1] = -1
+    ids[:, [3, 700]] = [11, 22]
+    ids = jnp.asarray(ids, jnp.int32)
+    bias = jnp.sum(q ** 2, axis=1, keepdims=True)
+    run_d = jnp.sort(jnp.asarray(rng.random((b, k)) * 50.0, jnp.float32), 1)
+    run_i = jnp.asarray(rng.integers(0, 10**6, (b, k)), jnp.int32)
+    kth = run_d[:, -1:]
+    args = (q, vecs, sqn, ids, bias, kth, run_d, run_i)
+    tiled = ops.bucket_probe(*args, bc=256)      # 5 tiles, last one padded
+    whole = ops.bucket_probe(*args, bc=2048)     # one tile of all 1100
+    for got, want in zip(tiled, whole):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("c,bc,tile", [
+    (390, 512, 390),      # fits one tile: the whole bucket, no padding
+    (2048, 512, 512),     # divides evenly
+    (1560, 512, 512),     # 4 tiles of 390 each need 512 lanes
+    (1100, 512, 384),     # 3 tiles: 1152 columns, not 3 x 512 = 1536
+    (1100, 256, 256),     # 5 tiles of 220 each need 256 lanes
+])
+def test_bucket_tile_width(c, bc, tile):
+    assert ops.bucket_tile(c, bc) == tile

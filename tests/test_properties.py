@@ -1,5 +1,4 @@
-"""Property-based invariants (hypothesis; deterministic shim in
-tests/_vendor when the real package is absent):
+"""Property-based invariants (hypothesis):
 
   * collectives.merge_topk — idempotence, permutation-invariance of the
     candidate columns, and the +inf -> id -1 masking contract that keeps

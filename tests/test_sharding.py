@@ -7,6 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs, ckpt
 from repro.dist import sharding as sh
+from repro.launch.mesh import auto_mesh
 from repro.models import model_zoo
 from repro.utils import hlo as hlo_lib
 
@@ -14,7 +15,7 @@ from repro.utils import hlo as hlo_lib
 def _fake_mesh_161():
     # single-device mesh with production axis names: rules must degrade to
     # replication (divisibility check) without erroring.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", configs.ALL_ARCHS)
@@ -91,7 +92,7 @@ def test_elastic_restore_across_meshes(tmp_path):
 
 def test_sharded_flat_search_single_device():
     from repro.dist import collectives
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = auto_mesh((1,), ("model",))
     fn = collectives.make_sharded_flat_search(mesh, k=5)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(128, 8)), jnp.float32)

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import metrics
+from repro.launch.mesh import auto_mesh
 from repro.utils import hlo as hlo_lib
 from repro.utils import meshctx
 
@@ -17,7 +18,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_constrain_divisibility_degrades():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     with meshctx.use_mesh(mesh):
         x = jnp.ones((3, 7))  # nothing divides -> P(None, None)
         y = meshctx.constrain(x, "dp", "tp")
@@ -25,7 +26,7 @@ def test_constrain_divisibility_degrades():
 
 
 def test_sp_axis_gated():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     with meshctx.use_mesh(mesh, sp=False):
         assert meshctx._resolve(mesh, "sp") is None
     with meshctx.use_mesh(mesh, sp=True):
