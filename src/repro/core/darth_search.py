@@ -38,6 +38,7 @@ class DarthState:
     npred: jax.Array     # i32[B] #predictor invocations
     early: jax.Array     # bool[B] terminated by DARTH (vs natural/budget)
     steps: jax.Array     # i32[] loop steps executed
+    nbatch: jax.Array    # i32[] steps that ran the batched predictor
 
 
 def _features(engine: engines_lib.Engine, inner: Any) -> jax.Array:
@@ -57,40 +58,49 @@ def init_darth_state(engine: engines_lib.Engine, q: jax.Array,
         npred=jnp.zeros((b,), jnp.int32),
         early=jnp.zeros((b,), bool),
         steps=jnp.zeros((), jnp.int32),
+        nbatch=jnp.zeros((), jnp.int32),
     )
 
 
 def make_darth_body(engine: engines_lib.Engine, predictor: PredictorFn,
                     params: IntervalParams, r_t: jax.Array):
     """One Algorithm-1 iteration as a reusable jittable body (the serving
-    engine drives this directly; darth_search wraps it in a while_loop)."""
+    engine drives this directly; darth_search wraps it in a while_loop).
+    Its ops carry the named scopes `darth.probe` (the engine step) and
+    `darth.predict` (features, predictor and interval update) in their
+    metadata, so a device trace attributes them."""
     def body(st: DarthState) -> DarthState:
         prev_ndis = st.inner.ndis
-        inner = engine.step(engine.index, st.inner)
+        with jax.named_scope("darth.probe"):
+            inner = engine.step(engine.index, st.inner)
         idis = st.idis + (inner.ndis - prev_ndis)
         due = inner.active & (idis.astype(jnp.float32) >= st.pi)
+        fire = due.any()
 
         def with_pred(args):
             inner, idis, st_pi, st_rp, st_npred, st_early = args
-            feats = _features(engine, inner)
-            rp = jnp.clip(predictor(feats), 0.0, 1.0)
-            rp = jnp.where(due, rp, st_rp)
-            stop = due & (rp >= r_t)
-            new_inner = engines_lib.set_active(inner, inner.active & ~stop)
-            pi = jnp.where(due, next_interval(params, r_t, rp), st_pi)
-            idis2 = jnp.where(due, 0, idis)
-            return (new_inner, idis2, pi, rp, st_npred + due.astype(jnp.int32),
-                    st_early | stop)
+            with jax.named_scope("darth.predict"):
+                feats = _features(engine, inner)
+                rp = jnp.clip(predictor(feats), 0.0, 1.0)
+                rp = jnp.where(due, rp, st_rp)
+                stop = due & (rp >= r_t)
+                new_inner = engines_lib.set_active(inner,
+                                                   inner.active & ~stop)
+                pi = jnp.where(due, next_interval(params, r_t, rp), st_pi)
+                idis2 = jnp.where(due, 0, idis)
+                return (new_inner, idis2, pi, rp,
+                        st_npred + due.astype(jnp.int32), st_early | stop)
 
         def without_pred(args):
             inner, idis, st_pi, st_rp, st_npred, st_early = args
             return (inner, idis, st_pi, st_rp, st_npred, st_early)
 
         inner, idis, pi, rp, npred, early = jax.lax.cond(
-            due.any(), with_pred, without_pred,
+            fire, with_pred, without_pred,
             (inner, idis, st.pi, st.r_pred, st.npred, st.early))
         return DarthState(inner=inner, idis=idis, pi=pi, r_pred=rp,
-                          npred=npred, early=early, steps=st.steps + 1)
+                          npred=npred, early=early, steps=st.steps + 1,
+                          nbatch=st.nbatch + fire.astype(jnp.int32))
 
     return body
 

@@ -229,7 +229,8 @@ def init_state(index: IVFIndex, q: jax.Array, *, k: int,
 
 @jax.jit
 def probe_step(index: IVFIndex, s: IVFSearchState) -> IVFSearchState:
-    """Scan one bucket per active query; merge global top-k; bump counters."""
+    """Scan one bucket per active query; merge global top-k (named scope
+    `darth.merge`); bump counters."""
     b, k = s.topk_d.shape
     nprobe = s.probe_order.shape[1]
     pos = jnp.minimum(s.probe_pos, nprobe - 1)
@@ -267,15 +268,16 @@ def probe_step(index: IVFIndex, s: IVFSearchState) -> IVFSearchState:
         dist = jnp.where(hot[:, None], dist, PAD_DIST)
         sizes = jnp.where(hot, sizes, 0)
 
-    old_kth = s.topk_d[:, -1]
-    cand_d = jnp.concatenate([s.topk_d, dist], axis=1)
-    cand_i = jnp.concatenate([s.topk_i, ids], axis=1)
-    neg, sel = jax.lax.top_k(-cand_d, k)
-    new_d = -neg
-    new_i = jnp.take_along_axis(cand_i, sel, axis=1)
+    with jax.named_scope("darth.merge"):
+        old_kth = s.topk_d[:, -1]
+        cand_d = jnp.concatenate([s.topk_d, dist], axis=1)
+        cand_i = jnp.concatenate([s.topk_i, ids], axis=1)
+        neg, sel = jax.lax.top_k(-cand_d, k)
+        new_d = -neg
+        new_i = jnp.take_along_axis(cand_i, sel, axis=1)
 
-    inserts = jnp.sum(dist < old_kth[:, None], axis=1).astype(jnp.int32)
-    inserts = jnp.minimum(inserts, k)
+        inserts = jnp.sum(dist < old_kth[:, None], axis=1).astype(jnp.int32)
+        inserts = jnp.minimum(inserts, k)
     done_probes = s.probe_pos + s.active.astype(jnp.int32)
     return IVFSearchState(
         q=s.q, qsq=s.qsq, probe_order=s.probe_order, first_nn=s.first_nn,

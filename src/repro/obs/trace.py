@@ -24,6 +24,11 @@ This module is the host half of that story:
     ring's fixed shape adds no retraces. The slot dim leads, so
     dist.sharding.constrain_slots pins it host-local exactly like the
     rest of the chunk carry.
+  * ``span`` / ``SERVE_SPANS`` / ``DEVICE_SCOPES`` — the serve loop's
+    profiler spans (``jax.profiler.TraceAnnotation``, always emitted;
+    they land in a ``jax.profiler.trace`` capture on the same clock as
+    the device ops) and the ``jax.named_scope`` names the serving chunk
+    carries in its op metadata.
 
 Termination-reason taxonomy (docs/observability.md):
 
@@ -53,6 +58,7 @@ import dataclasses
 import json
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -63,6 +69,31 @@ TERMINATION_REASONS = ("interval_met", "engine_exhausted",
 #: trajectory entries before the predictor's first firing (r_pred's
 #: "never called" sentinel; mirrors DarthState.r_pred's init value)
 NO_PREDICTION = -1.0
+
+
+#: the profiler span around one DarthServer.serve call, and the prefix of
+#: its phases: admit (call start up to the first chunk), dispatch (the
+#: run_chunk call), sync (the host waiting on the active mask), harvest,
+#: hook (on_boundary and the drained swap), refill, finish (stats,
+#: metrics export, re-rank)
+SERVE_SPAN = "darth.serve"
+SERVE_SPANS = (SERVE_SPAN,) + tuple(
+    f"{SERVE_SPAN}.{phase}" for phase in (
+        "admit", "dispatch", "sync", "harvest", "hook", "refill", "finish"))
+
+#: jax.named_scope names in the serving chunk's op metadata: the engine
+#: step (core.darth_search), the IVF top-k merge inside it (index.ivf
+#: probe_step) and the batched predictor branch (core.darth_search)
+DEVICE_SCOPES = ("darth.probe", "darth.merge", "darth.predict")
+
+
+def span(phase: str = "") -> jax.profiler.TraceAnnotation:
+    """Profiler span ``darth.serve[.<phase>]``: a TraceMe event on the
+    profiler's clock while a ``jax.profiler.trace`` runs, about a
+    microsecond of host time otherwise (``set_metadata`` on the entered
+    span adds stats to the event)."""
+    return jax.profiler.TraceAnnotation(
+        f"{SERVE_SPAN}.{phase}" if phase else SERVE_SPAN)
 
 
 # ---------------------------------------------------------------------------
@@ -259,4 +290,5 @@ def load_trace(path: str, serve: Optional[int] = None) -> List[Dict]:
 
 
 __all__ = ["Span", "Tracer", "TERMINATION_REASONS", "NO_PREDICTION",
+           "SERVE_SPAN", "SERVE_SPANS", "DEVICE_SCOPES", "span",
            "traj_init", "traj_record", "traj_window", "load_trace"]

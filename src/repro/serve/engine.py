@@ -110,6 +110,7 @@ class HostStats:
     refills: int = 0
     truncated: int = 0           # admitted, harvested with a partial top-k
     ndis_harvested: int = 0      # sum of harvested slots' ndis counters
+    predictor_calls: int = 0     # sum of harvested slots' npred counters
     killed: bool = False         # fault injection: host died mid-serve
     abandoned: int = 0           # queued on this host, never admitted
     # difficulty-aware admission (serve.difficulty; all zero/empty when
@@ -137,6 +138,12 @@ class ServeStats:
     #                              partial top-k when max_engine_steps hit
     #                              (or their host was killed)
     ndis_harvested: int = 0      # sum of per-query ndis at harvest
+    # predictor evaluations a slot was due for (sum of per-query npred
+    # at harvest), and engine steps that ran the batched predictor over
+    # the whole pool (DarthState.nbatch): calls / (batches x num_slots)
+    # is the share of the batched predictor's work that was due
+    predictor_calls: int = 0
+    predictor_batches: int = 0
     hosts: List[HostStats] = dataclasses.field(default_factory=list)
     # difficulty-aware admission totals (sums of the HostStats fields;
     # all zero when the server runs untiered)
@@ -430,12 +437,15 @@ class _HostSlots:
                 topk_i: np.ndarray, ndis: np.ndarray, *,
                 truncated: bool = False, step: int = 0,
                 r_pred: Optional[np.ndarray] = None,
+                npred: Optional[np.ndarray] = None,
                 reason: Optional[str] = None,
                 obs: Optional[_ObsArrays] = None) -> int:
         """Pull the masked local slots' top-k into results; free the
         slots. The array arguments are the host's SLICE [nloc, ..] of
-        the device state. Raises if a slot's query already has a result
-        — every admitted query must be returned exactly once. The one
+        the device state; `npred`, when given, adds the harvested slots'
+        predictor calls to the host's counters like `ndis`. Raises if a
+        slot's query already has a result — every admitted query must be
+        returned exactly once. The one
         sanctioned exception is a hedge duplicate (TierConfig.hedge):
         its primary already returned, so a naturally-completed hedge
         UPGRADES the stored result (deeper search at a raised target)
@@ -461,7 +471,7 @@ class _HostSlots:
                                 == self.result_epoch.get(qid)):
                             self.results[qid] = (topk_d[s], topk_i[s])
                             self.result_epoch[qid] = int(self.slot_epoch[s])
-                            self.stats.ndis_harvested += int(ndis[s])
+                            self._count(s, ndis, npred)
                             self.stats.hedge_upgrades += 1
                             if self.tracer is not None:
                                 self.tracer.upgrade_terminal(
@@ -508,7 +518,7 @@ class _HostSlots:
                 continue
             self.results[qid] = (topk_d[s], topk_i[s])
             self.result_epoch[qid] = int(self.slot_epoch[s])
-            self.stats.ndis_harvested += int(ndis[s])
+            self._count(s, ndis, npred)
             if self.tracer is not None:
                 if truncated:
                     term_reason = trunc_reason
@@ -541,6 +551,13 @@ class _HostSlots:
         else:
             self.stats.completed += count
         return count
+
+    def _count(self, s: int, ndis: np.ndarray,
+               npred: Optional[np.ndarray]) -> None:
+        """Fold harvested local slot ``s``'s work counters into stats."""
+        self.stats.ndis_harvested += int(ndis[s])
+        if npred is not None:
+            self.stats.predictor_calls += int(npred[s])
 
     def kill(self, *, step: int = 0, epoch: int = 0) -> None:
         """Fault injection: this host's slot slice dies. Its queue is
@@ -895,7 +912,11 @@ class DarthServer:
         compaction runs its budgeted ticks (MutableIndex.compact_tick),
         and finished shadows are staged for the drained atomic swap
         (request_swap). It runs on the host while the devices idle at
-        the sync point, so its budget is one tick's worth of work."""
+        the sync point, so its budget is one tick's worth of work.
+
+        The call runs under the profiler span `darth.serve`, which
+        carries `predictor_calls`, `predictor_batches` and `num_slots`
+        as stats when a profile is being captured."""
         from repro.core import api as api_lib
 
         queries = np.asarray(queries, np.float32)
@@ -914,8 +935,18 @@ class DarthServer:
         with ctx:
             self._serving = True
             try:
-                return self._serve(queries, r_targets, max_engine_steps,
-                                   kill_hosts or {}, on_boundary)
+                with obs_trace.span() as call_span:
+                    results, stats = self._serve(
+                        queries, r_targets, max_engine_steps,
+                        kill_hosts or {}, on_boundary)
+                    if call_span.is_enabled():
+                        # the predictor counters ride the call's span,
+                        # so a profile alone gives their ratio
+                        call_span.set_metadata(
+                            predictor_calls=stats.predictor_calls,
+                            predictor_batches=stats.predictor_batches,
+                            num_slots=self.num_slots)
+                return results, stats
             finally:
                 self._serving = False
                 self.chunk_state = None
@@ -925,48 +956,19 @@ class DarthServer:
                on_boundary=None,
                ) -> Tuple[List[Optional[Tuple[np.ndarray, np.ndarray]]],
                           ServeStats]:
+        """The serve loop. Its phases run under the profiler spans
+        `darth.serve.<phase>` (obs.trace.SERVE_SPANS): admit, then per
+        chunk dispatch, sync, harvest, hook and refill, then finish."""
         import time
 
+        span = obs_trace.span
         tr = self.tracer
         mets = self.metrics
-        if tr is not None:
-            tr.begin()
-
-        # a swap left pending by a previous serve call (budget ran out
-        # mid-drain): the pool is empty now, apply before admitting
-        if self._pending_swap is not None:
-            self._apply_pending_swap()
-
         n, d = queries.shape
         b = self.num_slots
         sph = b // self.hosts
         stats = ServeStats()
         results: List[Optional[Tuple[np.ndarray, np.ndarray]]] = [None] * n
-
-        # Difficulty classification at admission: one host-side routing
-        # scan over the whole batch (serve.difficulty), before any query
-        # touches a slot. r_targets is copied because admission control
-        # may degrade targets in place.
-        is_hard = None
-        if self.tiers is not None:
-            from repro.serve import difficulty as difficulty_lib
-            scores = difficulty_lib.difficulty_scores(self.engine.index,
-                                                      queries)
-            is_hard = difficulty_lib.assign_tiers(scores, self.tiers)
-            r_targets = r_targets.copy()
-
-        # Striped query partition: host h owns queries h, h+H, h+2H, ...
-        # (hosts == 1 degrades to the single-controller FIFO). Each host
-        # loop owns slots [h*sph, (h+1)*sph) and only ever touches them.
-        hostslots = [
-            _HostSlots(h, h * sph, (h + 1) * sph,
-                       list(range(h, n, self.hosts)), queries, r_targets,
-                       self.interval_for_target, results,
-                       tiers=self.tiers, is_hard=is_hard, tracer=tr,
-                       epoch=self.engine_epoch,
-                       collect_samples=mets is not None)
-            for h in range(self.hosts)]
-        stats.hosts = [hl.stats for hl in hostslots]
         chunk_ms: List[float] = []
 
         def gather_inputs():
@@ -981,16 +983,18 @@ class DarthServer:
         def state_slices():
             """Host-side copies of the per-slot device outputs every host
             loop harvests from (one transfer, then pure local slicing).
+            ndis and the predictor counts npred come in one fetch.
             r_pred (the predictor's recall estimate at harvest) is only
             fetched when the tier SLO stats, metrics, or tracer need it;
-            the tracer additionally drains the early mask, predictor
-            counts, and the trajectory ring AT THIS SAME boundary — no
-            extra sync points."""
+            the tracer additionally drains the early mask and the
+            trajectory ring AT THIS SAME boundary — no extra sync
+            points."""
             topk_d = np.asarray(jax.device_get(
                 self.engine.topk_d(st.inner)))
             topk_i = np.asarray(jax.device_get(
                 self.engine.topk_i(st.inner)))
-            ndis = np.asarray(jax.device_get(st.inner.ndis))
+            ndis, npred = (np.asarray(a) for a in jax.device_get(
+                (st.inner.ndis, st.npred)))
             need_rp = (self.tiers is not None or tr is not None
                        or mets is not None)
             r_pred = (np.asarray(jax.device_get(st.r_pred))
@@ -999,15 +1003,15 @@ class DarthServer:
             if tr is not None:
                 obs = _ObsArrays(
                     early=np.asarray(jax.device_get(st.early)),
-                    npred=np.asarray(jax.device_get(st.npred)),
+                    npred=npred,
                     traj=np.asarray(jax.device_get(traj)),
                     traj_base=traj_base)
-            return topk_d, topk_i, ndis, r_pred, obs
+            return topk_d, topk_i, ndis, npred, r_pred, obs
 
         def harvest_host(hl: _HostSlots, mask_local: np.ndarray,
                          arrays, *, truncated: bool = False,
                          reason: Optional[str] = None) -> int:
-            topk_d, topk_i, ndis, r_pred, obs = arrays
+            topk_d, topk_i, ndis, npred, r_pred, obs = arrays
             sl = slice(hl.lo, hl.hi)
             obs_loc = None
             if obs is not None:
@@ -1018,37 +1022,74 @@ class DarthServer:
                               truncated=truncated,
                               step=stats.engine_steps,
                               r_pred=None if r_pred is None else r_pred[sl],
-                              reason=reason, obs=obs_loc)
+                              npred=npred[sl], reason=reason, obs=obs_loc)
 
-        # initial fill: every host admits into all of its slots
-        fills = [hl.fill(np.arange(sph), step=0, epoch=self.engine_epoch)
-                 for hl in hostslots]
-        qb = np.concatenate([f[1] for f in fills])
-        rt, ipi, mpi = gather_inputs()
-        traj = None
-        traj_base = 0          # engine_steps at the ring's last rebuild
-        if tr is None:
-            st = self._init_chunk(self.engine.index, self._put(qb),
-                                  self._put(ipi), self._put(mpi))
-        else:
-            st, traj = self._init_chunk(self.engine.index, self._put(qb),
-                                        self._put(ipi), self._put(mpi))
-        # slots with no query: deactivate
-        occupied = occupied_global()
-        st = dataclasses.replace(
-            st, inner=engines_lib.set_active(
-                st.inner, st.inner.active & self._put(occupied)))
-        rt_dev = self._put(rt)
+        with span("admit"):
+            if tr is not None:
+                tr.begin()
+
+            # a swap left pending by a previous serve call (budget ran
+            # out mid-drain): the pool is empty now, apply before
+            # admitting
+            if self._pending_swap is not None:
+                self._apply_pending_swap()
+
+            # Difficulty classification at admission: one host-side
+            # routing scan over the whole batch (serve.difficulty),
+            # before any query touches a slot. r_targets is copied
+            # because admission control may degrade targets in place.
+            is_hard = None
+            if self.tiers is not None:
+                from repro.serve import difficulty as difficulty_lib
+                scores = difficulty_lib.difficulty_scores(self.engine.index,
+                                                          queries)
+                is_hard = difficulty_lib.assign_tiers(scores, self.tiers)
+                r_targets = r_targets.copy()
+
+            # Striped query partition: host h owns queries h, h+H, h+2H,
+            # ... (hosts == 1 degrades to the single-controller FIFO).
+            # Each host loop owns slots [h*sph, (h+1)*sph) and only ever
+            # touches them.
+            hostslots = [
+                _HostSlots(h, h * sph, (h + 1) * sph,
+                           list(range(h, n, self.hosts)), queries, r_targets,
+                           self.interval_for_target, results,
+                           tiers=self.tiers, is_hard=is_hard, tracer=tr,
+                           epoch=self.engine_epoch,
+                           collect_samples=mets is not None)
+                for h in range(self.hosts)]
+            stats.hosts = [hl.stats for hl in hostslots]
+
+            # initial fill: every host admits into all of its slots
+            fills = [hl.fill(np.arange(sph), step=0, epoch=self.engine_epoch)
+                     for hl in hostslots]
+            qb = np.concatenate([f[1] for f in fills])
+            rt, ipi, mpi = gather_inputs()
+            traj = None
+            traj_base = 0          # engine_steps at the ring's last rebuild
+            if tr is None:
+                st = self._init_chunk(self.engine.index, self._put(qb),
+                                      self._put(ipi), self._put(mpi))
+            else:
+                st, traj = self._init_chunk(self.engine.index, self._put(qb),
+                                            self._put(ipi), self._put(mpi))
+            # slots with no query: deactivate
+            occupied = occupied_global()
+            st = dataclasses.replace(
+                st, inner=engines_lib.set_active(
+                    st.inner, st.inner.active & self._put(occupied)))
+            rt_dev = self._put(rt)
 
         while True:
             t0 = time.perf_counter()
-            if tr is None:
-                st = self._run_chunk(self.engine.index, st, rt_dev,
-                                     self._put(ipi), self._put(mpi))
-            else:
-                st, traj = self._run_chunk(self.engine.index, st, traj,
-                                           rt_dev, self._put(ipi),
-                                           self._put(mpi))
+            with span("dispatch"):
+                if tr is None:
+                    st = self._run_chunk(self.engine.index, st, rt_dev,
+                                         self._put(ipi), self._put(mpi))
+                else:
+                    st, traj = self._run_chunk(self.engine.index, st, traj,
+                                               rt_dev, self._put(ipi),
+                                               self._put(mpi))
             stats.engine_steps += self.steps_per_sync
             for hl in hostslots:
                 hl.stats.slot_steps += (self.steps_per_sync
@@ -1057,65 +1098,70 @@ class DarthServer:
             dying = [hl for hl in hostslots
                      if hl.alive and hl.host in kill_hosts
                      and stats.engine_steps >= kill_hosts[hl.host]]
-            active = np.asarray(jax.device_get(st.inner.active))
+            with span("sync"):
+                active = np.asarray(jax.device_get(st.inner.active))
             # chunk wall time: dispatch + the sync-boundary fetch that
             # forces the device round-trip
             chunk_ms.append((time.perf_counter() - t0) * 1e3)
             finished = occupied & ~active
-            arrays = (state_slices()
-                      if finished.any() or dying else None)
             changed = False
-            for hl in dying:
-                # slots that finished at this very boundary hold a full
-                # top-k: they completed, only the still-running slots
-                # are truncated — then harvest those too, so no
-                # admitted query is dropped
-                sl = slice(hl.lo, hl.hi)
-                fin_local = hl.occupied & ~active[sl]
-                if fin_local.any():
-                    harvest_host(hl, fin_local, arrays)
-                if hl.occupied.any():
-                    harvest_host(hl, hl.occupied, arrays, truncated=True,
-                                 reason="host_killed")
-                hl.kill(step=stats.engine_steps, epoch=self.engine_epoch)
-                changed = True
-            if finished.any():
-                for hl in hostslots:
-                    if not hl.alive:
-                        continue
+            with span("harvest"):
+                arrays = (state_slices()
+                          if finished.any() or dying else None)
+                for hl in dying:
+                    # slots that finished at this very boundary hold a
+                    # full top-k: they completed, only the still-running
+                    # slots are truncated — then harvest those too, so
+                    # no admitted query is dropped
                     sl = slice(hl.lo, hl.hi)
                     fin_local = hl.occupied & ~active[sl]
                     if fin_local.any():
                         harvest_host(hl, fin_local, arrays)
-                        changed = True
+                    if hl.occupied.any():
+                        harvest_host(hl, hl.occupied, arrays, truncated=True,
+                                     reason="host_killed")
+                    hl.kill(step=stats.engine_steps, epoch=self.engine_epoch)
+                    changed = True
+                if finished.any():
+                    for hl in hostslots:
+                        if not hl.alive:
+                            continue
+                        sl = slice(hl.lo, hl.hi)
+                        fin_local = hl.occupied & ~active[sl]
+                        if fin_local.any():
+                            harvest_host(hl, fin_local, arrays)
+                            changed = True
             # chunk boundary: mutation / compaction hook, then the
             # drained atomic swap — the pool is retargeted only when NO
             # slot is in flight, so every admitted query runs start to
             # finish against one index version (its admission epoch)
-            self.boundary_step = stats.engine_steps
-            self.chunk_state = st
-            if on_boundary is not None:
-                swap_was_pending = self._pending_swap is not None
-                on_boundary(self)
-                if (tr is not None and not swap_was_pending
-                        and self._pending_swap is not None):
-                    tr.event("swap_staged", step=stats.engine_steps,
-                             epoch=self.engine_epoch)
-            if (self._pending_swap is not None
-                    and not any(hl.occupied.any() for hl in hostslots)):
-                self._apply_pending_swap()
-                stats.swaps += 1
-                if tr is not None:
-                    tr.event("swap_applied", step=stats.engine_steps,
-                             epoch=self.engine_epoch)
-                # chunk state was built against the OLD index (shapes
-                # may differ — e.g. HNSW visited rows grow at
-                # compaction); force a full init rebuild at the refill
-                st = None
-                self.chunk_state = None
-                traj = None
-                changed = False
-                occupied = occupied_global()
+            with span("hook"):
+                self.boundary_step = stats.engine_steps
+                self.chunk_state = st
+                if on_boundary is not None:
+                    swap_was_pending = self._pending_swap is not None
+                    on_boundary(self)
+                    if (tr is not None and not swap_was_pending
+                            and self._pending_swap is not None):
+                        tr.event("swap_staged", step=stats.engine_steps,
+                                 epoch=self.engine_epoch)
+                if (self._pending_swap is not None
+                        and not any(hl.occupied.any() for hl in hostslots)):
+                    self._apply_pending_swap()
+                    stats.swaps += 1
+                    if tr is not None:
+                        tr.event("swap_applied", step=stats.engine_steps,
+                                 epoch=self.engine_epoch)
+                    # chunk state was built against the OLD index (shapes
+                    # may differ — e.g. HNSW visited rows grow at
+                    # compaction); force a full init rebuild at the
+                    # refill, keeping the batched-predictor count first
+                    stats.predictor_batches += int(jax.device_get(st.nbatch))
+                    st = None
+                    self.chunk_state = None
+                    traj = None
+                    changed = False
+                    occupied = occupied_global()
             # per-host refill — unless the step budget is already
             # exhausted: a query spliced in now would run zero steps
             # and be harvested below as init-state junk (ids -1)
@@ -1127,63 +1173,66 @@ class DarthServer:
             # While a swap is pending, admissions pause: already-running
             # slots drain against their pinned epoch, new queries wait
             # for the new index.
-            if (stats.engine_steps < max_engine_steps
-                    and self._pending_swap is None):
-                if self.tiers is not None and self.tiers.rebalance:
-                    self._rebalance(hostslots, step=stats.engine_steps)
-                hedging = self.tiers is not None and self.tiers.hedge
-                mask = np.zeros((b,), bool)
-                qb2 = np.zeros((b, d), np.float32)
-                for hl in hostslots:
-                    if not hl.alive or not (hl.pending or hedging):
-                        continue
-                    free = np.nonzero(~hl.occupied)[0]
-                    if free.size == 0:
-                        continue
-                    m_loc, q_loc = hl.fill(free, step=stats.engine_steps,
-                                           epoch=self.engine_epoch)
-                    if m_loc.any():
-                        hl.stats.refills += 1
-                        mask[hl.lo:hl.hi] = m_loc
-                        qb2[hl.lo:hl.hi] = q_loc
-                if mask.any():
-                    rt, ipi, mpi = gather_inputs()
-                    rt_dev = self._put(rt)
-                    fresh = self._init_chunk(self.engine.index,
-                                             self._put(qb2),
-                                             self._put(ipi),
-                                             self._put(mpi))
-                    # after a drained swap st is None (old chunk state
-                    # discarded): the pool is empty, so the fresh init
-                    # IS the chunk state — no splice needed. With a
-                    # tracer, fresh is (state, ring) and the splice
-                    # selects both per slot (a spliced slot's ring row
-                    # resets to NO_PREDICTION, clearing the previous
-                    # occupant's trajectory); on a full rebuild the
-                    # ring's column origin moves to the current step
-                    # (traj_base) since state.steps restarts at 0.
-                    if tr is None:
-                        st = (fresh if st is None
-                              else self._splice(self._put(mask), fresh, st))
-                    elif st is None:
-                        st, traj = fresh
-                        traj_base = stats.engine_steps
-                    else:
-                        st, traj = self._splice(self._put(mask), fresh,
-                                                (st, traj))
-                    changed = True
+            with span("refill"):
+                if (stats.engine_steps < max_engine_steps
+                        and self._pending_swap is None):
+                    if self.tiers is not None and self.tiers.rebalance:
+                        self._rebalance(hostslots, step=stats.engine_steps)
+                    hedging = self.tiers is not None and self.tiers.hedge
+                    mask = np.zeros((b,), bool)
+                    qb2 = np.zeros((b, d), np.float32)
+                    for hl in hostslots:
+                        if not hl.alive or not (hl.pending or hedging):
+                            continue
+                        free = np.nonzero(~hl.occupied)[0]
+                        if free.size == 0:
+                            continue
+                        m_loc, q_loc = hl.fill(free, step=stats.engine_steps,
+                                               epoch=self.engine_epoch)
+                        if m_loc.any():
+                            hl.stats.refills += 1
+                            mask[hl.lo:hl.hi] = m_loc
+                            qb2[hl.lo:hl.hi] = q_loc
+                    if mask.any():
+                        rt, ipi, mpi = gather_inputs()
+                        rt_dev = self._put(rt)
+                        fresh = self._init_chunk(self.engine.index,
+                                                 self._put(qb2),
+                                                 self._put(ipi),
+                                                 self._put(mpi))
+                        # after a drained swap st is None (old chunk
+                        # state discarded): the pool is empty, so the
+                        # fresh init IS the chunk state — no splice
+                        # needed. With a tracer, fresh is (state, ring)
+                        # and the splice selects both per slot (a
+                        # spliced slot's ring row resets to
+                        # NO_PREDICTION, clearing the previous occupant's
+                        # trajectory); on a full rebuild the ring's
+                        # column origin moves to the current step
+                        # (traj_base) since state.steps restarts at 0.
+                        if tr is None:
+                            st = (fresh if st is None
+                                  else self._splice(self._put(mask), fresh,
+                                                    st))
+                        elif st is None:
+                            st, traj = fresh
+                            traj_base = stats.engine_steps
+                        else:
+                            st, traj = self._splice(self._put(mask), fresh,
+                                                    (st, traj))
+                        changed = True
+                if st is not None and changed:
+                    # deactivate empty (and dead-host) slots
+                    occupied = occupied_global()
+                    st = dataclasses.replace(
+                        st, inner=engines_lib.set_active(
+                            st.inner, st.inner.active & self._put(occupied)))
             if st is None:
                 # a swap drained the pool and the refill admitted
                 # nothing (budget exhausted, or the only pending
                 # queries sit on dead hosts): there is no chunk state
                 # left to step — exit; unadmitted queries stay None
                 break
-            if changed:
-                # deactivate empty (and dead-host) slots
-                occupied = occupied_global()
-                st = dataclasses.replace(
-                    st, inner=engines_lib.set_active(
-                        st.inner, st.inner.active & self._put(occupied)))
             if (not occupied.any()
                     and not any(hl.pending for hl in hostslots)):
                 break
@@ -1194,50 +1243,55 @@ class DarthServer:
                 # None). Queries never admitted from the queue remain
                 # None: they have no state to harvest.
                 if occupied.any():
-                    arrays = state_slices()
-                    for hl in hostslots:
-                        if hl.occupied.any():
-                            harvest_host(hl, hl.occupied, arrays,
-                                         truncated=True)
+                    with span("harvest"):
+                        arrays = state_slices()
+                        for hl in hostslots:
+                            if hl.occupied.any():
+                                harvest_host(hl, hl.occupied, arrays,
+                                             truncated=True)
                 break
 
-        for hl in hostslots:
-            if hl.alive:
-                hl.stats.abandoned = hl.pending
-                if tr is not None:
-                    # queued to the end (step budget ran out before
-                    # admission): close them out so the trace ledger
-                    # stays exhaustive — served ∪ shed ∪ abandoned
-                    for qid in hl.queue_easy + hl.queue_hard:
-                        tr.terminal(
-                            qid, "abandoned", host=hl.host,
-                            step=stats.engine_steps,
-                            epoch=self.engine_epoch, cause="budget",
-                            target=float(hl.r_targets[qid]),
-                            tier=hl._tier_of(qid))
-            stats.completed += hl.stats.completed
-            stats.slot_steps += hl.stats.slot_steps
-            stats.refills += hl.stats.refills
-            stats.truncated += hl.stats.truncated
-            stats.ndis_harvested += hl.stats.ndis_harvested
-            stats.shed += hl.stats.shed
-            stats.degraded += hl.stats.degraded
-            stats.hedged += hl.stats.hedged
-            stats.hedge_upgrades += hl.stats.hedge_upgrades
-            stats.hedge_epoch_dropped += hl.stats.hedge_epoch_dropped
-        stats.chunk_ms_p50 = obs_stats.p50(chunk_ms)
-        stats.chunk_ms_p99 = obs_stats.p99(chunk_ms)
-        if self.tiers is not None:
-            stats.tiers = _finalize_tiers(hostslots, is_hard)
-        if mets is not None:
-            self._export_metrics(mets, stats, hostslots, chunk_ms)
-        if tr is not None:
-            tr.finish()
-        if self.rerank is not None:
-            for qid, r in enumerate(results):
-                if r is not None:
-                    results[qid] = self.rerank(
-                        np.asarray(queries[qid], np.float32), r[1])
+        with span("finish"):
+            if st is not None:
+                stats.predictor_batches += int(jax.device_get(st.nbatch))
+            for hl in hostslots:
+                if hl.alive:
+                    hl.stats.abandoned = hl.pending
+                    if tr is not None:
+                        # queued to the end (step budget ran out before
+                        # admission): close them out so the trace ledger
+                        # stays exhaustive — served ∪ shed ∪ abandoned
+                        for qid in hl.queue_easy + hl.queue_hard:
+                            tr.terminal(
+                                qid, "abandoned", host=hl.host,
+                                step=stats.engine_steps,
+                                epoch=self.engine_epoch, cause="budget",
+                                target=float(hl.r_targets[qid]),
+                                tier=hl._tier_of(qid))
+                stats.completed += hl.stats.completed
+                stats.slot_steps += hl.stats.slot_steps
+                stats.refills += hl.stats.refills
+                stats.truncated += hl.stats.truncated
+                stats.ndis_harvested += hl.stats.ndis_harvested
+                stats.predictor_calls += hl.stats.predictor_calls
+                stats.shed += hl.stats.shed
+                stats.degraded += hl.stats.degraded
+                stats.hedged += hl.stats.hedged
+                stats.hedge_upgrades += hl.stats.hedge_upgrades
+                stats.hedge_epoch_dropped += hl.stats.hedge_epoch_dropped
+            stats.chunk_ms_p50 = obs_stats.p50(chunk_ms)
+            stats.chunk_ms_p99 = obs_stats.p99(chunk_ms)
+            if self.tiers is not None:
+                stats.tiers = _finalize_tiers(hostslots, is_hard)
+            if mets is not None:
+                self._export_metrics(mets, stats, hostslots, chunk_ms)
+            if tr is not None:
+                tr.finish()
+            if self.rerank is not None:
+                for qid, r in enumerate(results):
+                    if r is not None:
+                        results[qid] = self.rerank(
+                            np.asarray(queries[qid], np.float32), r[1])
         return results, stats
 
     def _export_metrics(self, mets, stats: ServeStats,

@@ -2,8 +2,9 @@
 registry, the trace-span/trajectory-ring contracts, traced-serve parity,
 the trace-ledger property (exactly one terminal per admitted query, under
 host kills and mid-serve hot-swaps), the mixed-target acceptance scenario
-(hosts {1, 2}, ivf + hnsw, hedging + one online compaction swap) and the
-explain CLI."""
+(hosts {1, 2}, ivf + hnsw, hedging + one online compaction swap), the
+explain CLI, and the serve loop's profiler spans, device scopes and
+predictor counters."""
 import json
 
 import numpy as np
@@ -573,3 +574,145 @@ def test_explain_story_and_cli(obs_setup, tmp_path, capsys):
     assert f"query {worst.qid}:" in capsys.readouterr().out
     assert explain_lib.explain([]) == \
         "trace holds no terminal spans (nothing was served?)"
+
+
+# -- profiler spans, device scopes, predictor counters ---------------------
+
+def _server(d, **kw):
+    return DarthServer(d.engine, d.trained.predictor, d.interval_for_target,
+                       num_slots=8, steps_per_sync=2, **kw)
+
+
+def _profiled_serve(server, queries, rts, trace_dir):
+    """Serve under jax.profiler.trace; (results, stats, host spans named
+    darth.serve*, as (name, start_ns, end_ns, stats))."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(trace_dir)):
+        res, stats = server.serve(queries, rts)
+    path, = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(trace_lib.SERVE_SPAN)]
+    return res, stats, spans
+
+
+def test_serve_emits_profiler_spans_nested_in_the_call(obs_setup, tmp_path):
+    """One darth.serve span per call, carrying the predictor counters;
+    dispatch and sync once per chunk; every phase inside the call."""
+    ds, index, d = obs_setup
+    rts = np.tile([0.8, 0.9, 0.95, 0.7], 16).astype(np.float32)
+    res, stats, spans = _profiled_serve(_server(d), ds.queries, rts,
+                                        tmp_path)
+    names = [s[0] for s in spans]
+    assert set(names) <= set(trace_lib.SERVE_SPANS)
+    assert names.count("darth.serve") == 1
+    chunks = stats.engine_steps // 2
+    assert names.count("darth.serve.dispatch") == chunks
+    assert names.count("darth.serve.sync") == chunks
+    assert names.count("darth.serve.admit") == 1
+    assert names.count("darth.serve.finish") == 1
+    for phase in ("harvest", "hook", "refill"):
+        assert names.count(f"darth.serve.{phase}") >= chunks - 1
+    call = next(s for s in spans if s[0] == "darth.serve")
+    for name, t0, t1, _ in spans:
+        assert call[1] <= t0 <= t1 <= call[2], name
+    assert call[3] == {"predictor_calls": stats.predictor_calls,
+                       "predictor_batches": stats.predictor_batches,
+                       "num_slots": 8}
+    assert stats.completed == 64 and all(r is not None for r in res)
+
+
+def test_run_chunk_carries_device_scopes(obs_setup):
+    """The probe step, its top-k merge and the predictor branch carry
+    their named scopes in the chunk program's op metadata."""
+    ds, index, d = obs_setup
+    server = _server(d)
+    rt = np.full((8,), 0.9, np.float32)
+    p = d.interval_for_target(rt)
+    st = server._init_chunk(index, jnp.asarray(ds.queries[:8]),
+                            jnp.asarray(p.ipi), jnp.asarray(p.mpi))
+    lowered = server._run_chunk.lower(index, st, jnp.asarray(rt),
+                                      jnp.asarray(p.ipi), jnp.asarray(p.mpi))
+    text = lowered.as_text(debug_info=True)
+    for scope in trace_lib.DEVICE_SCOPES:
+        assert f'loc("{scope}/' in text or f"/{scope}/" in text, scope
+    # the compiled ops' op_name paths nest the merge inside the probe
+    hlo = lowered.compile().as_text()
+    assert 'op_name="jit(run_chunk)/while/body/' in hlo
+    assert "/darth.probe/jit(probe_step)/darth.merge/" in hlo
+    assert "/cond/branch_1_fun/darth.predict/" in hlo
+
+
+def test_predictor_counters_match_the_trace_and_untraced_serve(obs_setup):
+    """predictor_calls is the sum of the queries' npred; the batched
+    predictor ran on at least as many steps as the busiest query and on
+    no more than every step; tracing changes neither counter nor any
+    result."""
+    ds, index, d = obs_setup
+    rts = np.tile([0.7, 0.9, 0.8, 0.95], 16).astype(np.float32)
+    ref, ref_stats = _server(d).serve(ds.queries, rts)
+    tracer = trace_lib.Tracer(traj_cap=32)
+    res, stats = _server(d, tracer=tracer).serve(ds.queries, rts)
+    npred = [s.attrs["npred"] for s in tracer.terminals().values()]
+    assert len(npred) == 64
+    assert stats.predictor_calls == sum(npred) > 0
+    assert max(npred) <= stats.predictor_batches <= stats.engine_steps
+    assert (ref_stats.predictor_calls, ref_stats.predictor_batches) == \
+        (stats.predictor_calls, stats.predictor_batches)
+    assert sum(h.predictor_calls for h in stats.hosts) == \
+        stats.predictor_calls
+    for a, b in zip(ref, res):
+        np.testing.assert_array_equal(a[0], b[0])
+        np.testing.assert_array_equal(a[1], b[1])
+
+
+def test_predictor_batches_survive_a_drained_swap(obs_setup):
+    """A drained swap discards the chunk state, and with it the
+    on-device batch count: the host adds what each state had counted."""
+    import jax
+
+    ds, index, d = obs_setup
+    server = _server(d)
+    seen = {}                         # engine epoch -> last nbatch seen
+
+    def hook(srv):
+        seen[srv.engine_epoch] = int(jax.device_get(
+            srv.chunk_state.nbatch))
+        if srv.boundary_step == 4:
+            srv.request_swap(engine=srv.engine)
+    _, stats = server.serve(ds.queries, np.full((64,), 0.9, np.float32),
+                            on_boundary=hook)
+    assert stats.swaps == 1 and stats.completed == 64
+    assert len(seen) == 2 and min(seen.values()) > 0
+    assert stats.predictor_batches == sum(seen.values())
+
+
+def test_untraced_serve_fetches_no_more_per_boundary(obs_setup,
+                                                     monkeypatch):
+    """Per chunk boundary an untraced serve fetches the active mask, and
+    at a harvest topk_d, topk_i and (ndis, npred) together; once per call
+    it reads the batched-predictor count."""
+    import jax
+    from repro.serve import engine as engine_lib
+
+    ds, index, d = obs_setup
+    gets, harvests = [0], [0]
+    real_get, real_harvest = jax.device_get, engine_lib._HostSlots.harvest
+
+    def get(x):
+        gets[0] += 1
+        return real_get(x)
+
+    def harvest(self, *a, **kw):
+        harvests[0] += 1
+        return real_harvest(self, *a, **kw)
+    monkeypatch.setattr(jax, "device_get", get)
+    monkeypatch.setattr(engine_lib._HostSlots, "harvest", harvest)
+    _, stats = _server(d).serve(ds.queries, np.full((64,), 0.9, np.float32))
+    chunks = stats.engine_steps // 2
+    assert gets[0] == chunks + 3 * harvests[0] + 1
