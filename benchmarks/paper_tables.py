@@ -322,7 +322,8 @@ def feature_ablation() -> Tuple[Rows, str]:
     for name, cols in groups.items():
         p = gbdt.fit(xf[n_hold:][:, cols], y[n_hold:],
                      gbdt.GBDTConfig(num_trees=60, depth=5))
-        pred = np.asarray(gbdt.predict_jit(p, jnp.asarray(xf[:n_hold][:, cols])))
+        xh = jnp.asarray(xf[:n_hold][:, cols])
+        pred = np.asarray(gbdt.predict_efficient(p, xh))
         m = regression_metrics(pred, y[:n_hold])
         rows.append({"features": name, "mse": round(m["mse"], 5),
                      "r2": round(m["r2"], 3)})
@@ -346,12 +347,12 @@ def model_selection() -> Tuple[Rows, str]:
     xtr, ytr, xho, yho = xf[n_hold:], y[n_hold:], xf[:n_hold], y[:n_hold]
     rows = []
     p = gbdt.fit(xtr, ytr, gbdt.GBDTConfig(num_trees=100, depth=6))
-    rows.append(("gbdt", gbdt.predict_jit(p, jnp.asarray(xho))))
+    rows.append(("gbdt", gbdt.predict_efficient(p, jnp.asarray(xho))))
     p = gbdt.fit_random_forest(xtr[:60_000], ytr[:60_000], num_trees=40,
                                depth=6)
-    rows.append(("random_forest", gbdt.predict_jit(p, jnp.asarray(xho))))
+    rows.append(("random_forest", gbdt.predict_efficient(p, jnp.asarray(xho))))
     p = gbdt.fit_decision_tree(xtr, ytr, depth=8)
-    rows.append(("decision_tree", gbdt.predict_jit(p, jnp.asarray(xho))))
+    rows.append(("decision_tree", gbdt.predict_efficient(p, jnp.asarray(xho))))
     lm = gbdt.fit_linear(xtr, ytr)
     rows.append(("linear", lm.predict(jnp.asarray(xho))))
     out = []

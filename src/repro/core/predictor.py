@@ -1,10 +1,14 @@
 """Recall predictor wrapper: GBDT params + prediction paths.
 
 Two inference paths, numerically identical (tests assert it):
-  * XLA path (gbdt.infer.predict_efficient) — used on CPU and inside
-    lowered dry-run graphs,
-  * Pallas path (kernels.gbdt_predict) — VMEM-resident ensemble, compiled
-    on a TPU, interpreted on the CPU (kernels.backend).
+  * XLA path (gbdt.infer.predict_efficient), the default on every backend
+    and the one the serving loop runs: a gather-free descent by
+    compare-and-select (`jnp.where` and reductions, never a multiply,
+    which turns a degenerate node's infinite threshold into NaN, nor a
+    matmul, which rounds to bfloat16 on a TPU), fused into the step's
+    `darth.predict` branch,
+  * Pallas path (kernels.gbdt_predict, `use_kernel=True`) — VMEM-resident
+    ensemble, compiled on a TPU, interpreted on the CPU (kernels.backend).
 """
 from __future__ import annotations
 

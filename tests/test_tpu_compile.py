@@ -10,12 +10,14 @@ Kernels are compiled with `interpret=False` explicitly, since the
 backend resolver sees the CPU in this process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro import gbdt
 from repro.gbdt.model import GBDTParams
 from repro.index import ivf
 from repro.kernels import ops
@@ -79,16 +81,26 @@ def test_bucket_topk_compiles_sift1m_one_chip_cap(one_chip):
     _assert_kernel(_bucket_probe_compiled(one_chip, 1560, jnp.float32))
 
 
-def test_gbdt_predict_compiles(one_chip):
-    trees, depth, feats = 100, 6, 11
+def _served_predictor(sharding, trees=100, depth=6, feats=11):
     params = GBDTParams(
-        feat=_sds((trees, 2**depth - 1), jnp.int32, one_chip),
-        thresh=_sds((trees, 2**depth - 1), jnp.float32, one_chip),
-        leaf=_sds((trees, 2**depth), jnp.float32, one_chip),
-        base=_sds((), jnp.float32, one_chip))
+        feat=_sds((trees, 2**depth - 1), jnp.int32, sharding),
+        thresh=_sds((trees, 2**depth - 1), jnp.float32, sharding),
+        leaf=_sds((trees, 2**depth), jnp.float32, sharding),
+        base=_sds((), jnp.float32, sharding))
+    return params, _sds((B, feats), jnp.float32, sharding)
+
+
+def test_gbdt_predict_compiles(one_chip):
     fn = jax.jit(lambda p, x: ops.gbdt_predict(p, x, interpret=False))
-    _assert_kernel(fn.lower(
-        params, _sds((B, feats), jnp.float32, one_chip)).compile())
+    _assert_kernel(fn.lower(*_served_predictor(one_chip)).compile())
+
+
+def test_gbdt_descent_compiles_without_gather(one_chip):
+    """The served predictor's XLA descent stays select-and-reduce on the
+    TPU: a gather there lowers to near-serial element fetches."""
+    text = gbdt.predict_efficient.lower(
+        *_served_predictor(one_chip)).compile().as_text()
+    assert not re.search(r"\sgather\(", text)  # an op, not a name in metadata
 
 
 def test_sharded_probe_step_compiles_one_chip_mesh(topo):
